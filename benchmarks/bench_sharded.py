@@ -11,24 +11,18 @@ bench time too).
 
     PYTHONPATH=src python -m benchmarks.bench_sharded
 
-On a single-device host the bench re-executes itself in a subprocess
-under ``XLA_FLAGS=--xla_force_host_platform_device_count=<N>`` (the
-device count is locked at process start), so it exercises a real
-multi-device mesh anywhere — including CPU-only CI.
+It needs at least two devices and fails with a message on fewer.  On a
+CPU-only host the caller forces host devices before the process starts:
+``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 """
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import subprocess
 import sys
 import time
 from typing import Dict, List
 
 import numpy as np
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _parse(argv):
@@ -44,41 +38,7 @@ def _parse(argv):
     ap.add_argument("--methods", default="gemm,popcount,pallas,fused")
     ap.add_argument("--no-sweep", action="store_true",
                     help="skip the (V, W) crossover sweep")
-    ap.add_argument("--force-devices", type=int, default=8,
-                    help="host device count to force when respawning on a "
-                         "single-device machine")
-    ap.add_argument("--json-out", default=None, help=argparse.SUPPRESS)
     return ap.parse_args(argv)
-
-
-def _respawn(argv, force_devices: int) -> List[Dict]:
-    """Re-exec under a forced multi-device host; relay stdout, collect
-    the child's records from a JSON handoff file."""
-    out_path = os.path.join(REPO_ROOT, "results", "bench",
-                            "_sharded_child.json")
-    os.makedirs(os.path.dirname(out_path), exist_ok=True)
-    env = dict(os.environ)
-    # the force flag only multiplies CPU host devices: pin the child to
-    # the cpu platform so a host with one accelerator still gets a
-    # multi-device mesh (and can never loop back into _respawn)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " "
-                        "--xla_force_host_platform_device_count="
-                        f"{force_devices}").strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in ("src", env.get("PYTHONPATH")) if p)
-    r = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_sharded",
-         *(argv or []), "--json-out", out_path],
-        env=env, cwd=REPO_ROOT, text=True, capture_output=True)
-    sys.stdout.write(r.stdout)
-    if r.returncode != 0:
-        sys.stderr.write(r.stderr[-4000:])
-        raise RuntimeError("sharded bench child failed")
-    with open(out_path) as f:
-        records = json.load(f)
-    os.remove(out_path)
-    return records
 
 
 def main(argv: List[str] | None = None) -> List[Dict]:
@@ -86,20 +46,16 @@ def main(argv: List[str] | None = None) -> List[Dict]:
     import jax
 
     if len(jax.devices()) < 2:
-        if args.json_out:
-            # we ARE the respawned child (--json-out is the handoff
-            # marker): forcing devices didn't take, so fail loud instead
-            # of respawning forever
-            raise RuntimeError(
-                f"forced {args.force_devices} host devices but the child "
-                f"still sees {len(jax.devices())}; cannot run the sharded "
-                "bench on this host")
-        return _respawn(argv, args.force_devices)
+        raise RuntimeError(
+            f"bench_sharded needs >= 2 devices; this process sees "
+            f"{len(jax.devices())} ({jax.devices()[0].platform}).  On a "
+            "CPU-only host force them before start: JAX_PLATFORMS=cpu "
+            "XLA_FLAGS=--xla_force_host_platform_device_count=8")
 
     from repro.core import QueryContext, make_cooc_mesh, materialize
     from repro.data import synthetic_csl
     from repro.serve.cooc_engine import CoocEngine
-    from benchmarks.common import section, write_csv, write_json
+    from benchmarks.common import section, write_csv
 
     n_dev = len(jax.devices())
     methods = tuple(m for m in args.methods.split(",") if m)
@@ -206,11 +162,6 @@ def main(argv: List[str] | None = None) -> List[Dict]:
 
     path = write_csv("sharded", rows)
     print(f"CSV -> {path}")
-    if args.json_out:
-        # handoff file for the respawned child (read + unlinked by the
-        # parent): atomic commit so a crash mid-dump can't leave the
-        # parent a truncated half-record to parse
-        write_json(args.json_out, out)
     return out
 
 

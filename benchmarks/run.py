@@ -37,6 +37,9 @@ def main() -> int:
                          "exit 1 on any >20%% metric regression")
     args = ap.parse_args()
 
+    from repro.launch.flags import use_compile_cache
+    use_compile_cache()
+
     baseline = None
     if args.compare:
         from benchmarks.common import load_bench_baselines

@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.inverted_index import PackedIndex, incidence_dense
+from repro.core.inverted_index import PackedIndex, incidence_dense, transpose_pad
 from repro.core.network import CoocNetwork
 
 
@@ -320,7 +320,7 @@ def _frontier_counts(index: PackedIndex, masks: jax.Array, method: str,
     "gemm"     — unpack(masks) @ operands["x_dense"] on the MXU;
     "popcount" — AND + popcount over the packed bitmap, pure jnp (VPU);
     "pallas"   — the same popcount op through the tiled Pallas postings
-                 kernel (compiled on TPU, interpret mode elsewhere;
+                 kernel (compiled on TPU, interpret mode on the CPU;
                  padding to tile multiples handled by kernels.ops).
 
     With a ``mesh`` the same method runs term- or doc-sharded: per-shard
@@ -374,17 +374,27 @@ def _resolve_operands(index, method: str, x_dense: Optional[jax.Array],
         return constrain(incidence_dense(index, jnp.bfloat16),
                          ("docs", "terms"))
 
-    def _packed_t_pad_oneshot():
-        p = jnp.transpose(index.packed)
-        return jnp.pad(p, ((0, (-p.shape[0]) % 8), (0, (-p.shape[1]) % 128)))
-
     builders = {"x_dense": _x_dense_oneshot,
                 "packed_t": lambda: jnp.transpose(index.packed),
-                "packed_t_pad": _packed_t_pad_oneshot}
+                "packed_t_pad": lambda: transpose_pad(index.packed)}
     for name in needs:
         if name not in ops:
             ops[name] = builders[name]()
     return index, ops, mesh
+
+
+def _postings_rows(index: PackedIndex, operands: Mapping[str, jax.Array],
+                   terms: jax.Array) -> jax.Array:
+    """Postings bitmaps of ``terms`` as (n, W) rows.  A row gather from the
+    transposed artifact when the method carries one; else a column gather
+    from ``packed`` (XLA then keeps one column-major copy of the index for
+    the step — the methods without the artifact pay that)."""
+    pt = operands.get("packed_t_pad")
+    if pt is None:
+        pt = operands.get("packed_t")
+    if pt is not None:
+        return pt[terms, :index.n_words]
+    return jnp.take(index.packed, terms, axis=1).T
 
 
 def _expand_level(index: PackedIndex, state: BFSState, topk: int, dedup: bool,
@@ -458,7 +468,7 @@ def _expand_level(index: PackedIndex, state: BFSState, topk: int, dedup: bool,
     next_dst = flat_dst[cand_idx]
     next_parent = flat_parent[cand_idx]
     parent_masks = state.masks[next_parent]                     # (B, W)
-    post = index.packed.T[jnp.clip(next_dst, 0)]                # (B, W) gather columns
+    post = _postings_rows(index, operands, jnp.clip(next_dst, 0))  # (B, W)
     next_masks = jnp.where(next_valid[:, None], parent_masks & post, jnp.uint32(0))
     visited = state.visited
     if dedup:
@@ -505,7 +515,7 @@ def bfs_construct(index, seed_terms: jax.Array, *, depth: int,
       "fused"    — the whole level step (popcount + masking + top-k) as
                    ONE launch over the pre-padded transposed postings
                    (``kernels.level_step``; compiled Pallas on TPU, the
-                   fused XLA form elsewhere) — zero per-query padding.
+                   fused XLA form on the CPU) — zero per-query padding.
     All are exact (0/1 operands, fp32/int32 accumulation) and tested
     equal.
 
@@ -537,7 +547,8 @@ def bfs_construct(index, seed_terms: jax.Array, *, depth: int,
     seeds = jnp.clip(seed_terms, 0)
     masks0 = jnp.zeros((b, index.n_words), jnp.uint32)
     masks0 = masks0.at[:s].set(jnp.where(seed_valid[:, None],
-                                         index.packed.T[seeds], jnp.uint32(0)))
+                                         _postings_rows(index, ops, seeds),
+                                         jnp.uint32(0)))
     if scope_mask is not None:
         masks0 = masks0 & scope_mask[None, :]
     terms0 = jnp.full((b,), -1, jnp.int32).at[:s].set(jnp.where(seed_valid, seeds, -1))

@@ -48,6 +48,7 @@ the single-device implementation unchanged.
 """
 from __future__ import annotations
 
+import functools
 from typing import Mapping, Optional, Sequence, Tuple
 
 import jax
@@ -57,12 +58,15 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.inverted_index import PackedIndex, unpack_bitmap
 from repro.core.query import get_count_method
-from repro.launch.sharding import shard_map_compat as _smap
 
 #: physical mesh axes (launch/mesh.py convention; DEFAULT_RULES maps the
 #: logical "terms" axis onto "model" and "docs" onto "data")
 DOC_AXIS = "data"
 TERM_AXIS = "model"
+
+#: every sharded site maps with the replication check off: the popcount /
+#: all_gather compositions here don't all carry replication rules
+_smap = functools.partial(jax.shard_map, check_vma=False)
 
 
 def _round_up(n: int, mult: int) -> int:
@@ -159,7 +163,7 @@ def _needs(method: str, cooc_gemm: bool) -> Tuple[str, ...]:
         return ("x_dense",)
     if method == "fused":
         # under a mesh the fused method counts straight off the LOCAL
-        # packed shard (its fn's no-artifact fallback): the pre-padded
+        # packed shard (its fn's no-artifact form): the pre-padded
         # (V->8) artifact's layout need not divide the shard count, and
         # per-shard top-k replaces the fused kernel's merge anyway
         return ()
@@ -219,7 +223,7 @@ def sharded_counts(index: PackedIndex, masks: jax.Array, method: str,
                               dict(zip(needs, xs)))
             return _tiled_all_gather(c, TERM_AXIS, axis=1, tile_axis=0)
 
-        out = _smap(local, mesh,
+        out = _smap(local, mesh=mesh,
                     in_specs=(P(), P(None, TERM_AXIS), P(TERM_AXIS), P(),
                               *specs),
                     out_specs=P(None, None))(
@@ -244,7 +248,7 @@ def sharded_counts(index: PackedIndex, masks: jax.Array, method: str,
                           dict(zip(needs, xs)))
         return jax.lax.psum(c, DOC_AXIS)
 
-    return _smap(local, mesh,
+    return _smap(local, mesh=mesh,
                  in_specs=(P(None, DOC_AXIS), P(DOC_AXIS, None), P(), P(),
                            *specs),
                  out_specs=P(None, None))(
@@ -285,7 +289,7 @@ def sharded_signatures(packed: jax.Array, a: jax.Array, b: jax.Array,
                                          perm_tile=perm_tile)
             return _tiled_all_gather(sig, TERM_AXIS, axis=0, tile_axis=1)
 
-        out = _smap(local, mesh,
+        out = _smap(local, mesh=mesh,
                     in_specs=(P(None, TERM_AXIS), P(), P(), P()),
                     out_specs=P(None, None))(packed_p, keys, a, b)
         return out[:v]
@@ -302,7 +306,7 @@ def sharded_signatures(packed: jax.Array, a: jax.Array, b: jax.Array,
                                      perm_tile=perm_tile)
         return jax.lax.pmin(sig, DOC_AXIS)
 
-    return _smap(local, mesh,
+    return _smap(local, mesh=mesh,
                  in_specs=(P(DOC_AXIS, None), P(), P()),
                  out_specs=P(None, None))(packed_p, a, b)
 
@@ -368,7 +372,7 @@ def sharded_block_topk(index: PackedIndex, masks: jax.Array, rows: jax.Array,
         w2, sel = jax.lax.top_k(w_all, k_fin)
         return w2, jnp.take_along_axis(i_all, sel, axis=1)
 
-    w2, i2 = _smap(local, mesh,
+    w2, i2 = _smap(local, mesh=mesh,
                    in_specs=(P(), P(), P(None, TERM_AXIS), P(TERM_AXIS),
                              P(), *specs),
                    out_specs=(P(None, None), P(None, None)))(
@@ -446,7 +450,7 @@ def sharded_level_topk(index: PackedIndex, masks: jax.Array,
             w2, sel = jax.lax.top_k(w_all, k_eff)
             return w2, jnp.take_along_axis(i_all, sel, axis=1)
 
-        w2, i2 = _smap(local, mesh,
+        w2, i2 = _smap(local, mesh=mesh,
                        in_specs=(P(), P(), P(), P(TERM_AXIS),
                                  P(None, TERM_AXIS), P(TERM_AXIS), P(),
                                  *specs),
@@ -527,7 +531,7 @@ def sharded_row_block_topk(index: PackedIndex, packed_t: jax.Array,
         return (jax.lax.all_gather(w, ax, axis=0, tiled=True),
                 jax.lax.all_gather(i, ax, axis=0, tiled=True))
 
-    return _smap(local, mesh,
+    return _smap(local, mesh=mesh,
                  in_specs=(P(ax), P(), P(), P(), P(), P(),
                            *(P() for _ in needs)),
                  out_specs=(P(None, None), P(None, None)))(

@@ -77,6 +77,15 @@ def pack_docs(doc_terms: Sequence[Sequence[int]], vocab_size: int,
     This is the offline ingest path: tokenisation has already happened in
     ``repro.data``; here we only pack term ids into postings bitmaps.
     """
+    packed, df, n_docs = _pack_host(doc_terms, vocab_size, capacity)
+    return PackedIndex(jnp.asarray(packed), jnp.asarray(df), jnp.asarray(n_docs, jnp.int32))
+
+
+def _pack_host(doc_terms: Sequence[Sequence[int]], vocab_size: int,
+               capacity: Optional[int] = None
+               ) -> Tuple[np.ndarray, np.ndarray, np.int32]:
+    """:func:`pack_docs` in host memory: (packed, doc_freq, n_docs) as
+    numpy, for a caller that places the arrays itself."""
     n_docs = len(doc_terms)
     cap = capacity if capacity is not None else n_docs
     cap = max(cap, n_docs)
@@ -88,7 +97,7 @@ def pack_docs(doc_terms: Sequence[Sequence[int]], vocab_size: int,
         uniq = uniq[(uniq >= 0) & (uniq < vocab_size)]
         packed[d // 32, uniq] |= np.uint32(1) << np.uint32(d % 32)
         df[uniq] += 1
-    return PackedIndex(jnp.asarray(packed), jnp.asarray(df), jnp.asarray(n_docs, jnp.int32))
+    return packed, df, np.int32(n_docs)
 
 
 def grow_capacity(index: PackedIndex, min_capacity: int) -> PackedIndex:
@@ -131,6 +140,15 @@ def grow_vocab(index: PackedIndex, min_vocab: int) -> PackedIndex:
     packed = jnp.pad(index.packed, ((0, 0), (0, v - index.vocab_size)))
     df = jnp.pad(index.doc_freq, (0, v - index.vocab_size))
     return PackedIndex(packed, df, index.n_docs)
+
+
+@jax.jit
+def transpose_pad(packed: jax.Array) -> jax.Array:
+    """The fused level step's postings layout: ``packed`` (W, V) transposed
+    to (V, W) and zero-padded to (V -> 8, W -> 128), the int32 TPU tile.
+    One compiled op, so no unpadded transpose is held beside the result."""
+    w, v = packed.shape
+    return jnp.pad(packed.T, ((0, (-v) % 8), (0, (-w) % 128)))
 
 
 def incidence_dense(index: PackedIndex, dtype=jnp.float32) -> jax.Array:
@@ -277,49 +295,47 @@ def ingest(index: PackedIndex, new_doc_terms: jax.Array, new_doc_valid: jax.Arra
     return ingest_at(index, new_doc_terms, new_doc_valid, doc_ids)
 
 
+@jax.jit
 def ingest_at(index: PackedIndex, new_doc_terms: jax.Array,
               new_doc_valid: jax.Array, doc_slots: jax.Array) -> PackedIndex:
     """Scatter a block of documents into EXPLICIT slot positions.
 
     The ring-write primitive behind sliding-window ingest: ``doc_slots``
     (N,) int32 names the target slot of each row (slots of invalid rows are
-    ignored).  Target slots must currently hold all-zero postings — either
-    never used, or cleared by :func:`retire_docs` — because the OR-scatter
-    below relies on the target bits being 0; ``QueryContext`` evicts before
-    it reuses.  ``n_docs`` advances to the new valid-slot high-water mark
-    (it never shrinks: slot ids are stable).
+    ignored; valid rows name distinct slots).  Target slots must currently
+    hold all-zero postings — either never used, or cleared by
+    :func:`retire_docs` — because the OR-scatter below relies on the
+    target bits being 0; ``QueryContext`` evicts before it reuses.
+    ``n_docs`` advances to the new valid-slot high-water mark (it never
+    shrinks: slot ids are stable).
     """
     n_new, m = new_doc_terms.shape
     if n_new == 0:
         return index
-    flat_terms = new_doc_terms.reshape(-1)
-    flat_docs = jnp.repeat(jnp.clip(doc_slots, 0), m)
-    valid = (flat_terms >= 0) & jnp.repeat(new_doc_valid, m)
 
-    # Dedupe (doc, term) pairs so each (doc, term) contributes one bit and
-    # one df count, regardless of within-doc term repetition.  Lexicographic
-    # sort on (valid, doc, term) — avoids int64 composite keys.
-    order = jnp.lexsort((jnp.clip(flat_terms, 0), flat_docs, ~valid))
-    d_s = flat_docs[order]
-    t_s = jnp.clip(flat_terms, 0)[order]
-    v_s = valid[order]
-    first = jnp.concatenate([
-        jnp.array([True]),
-        (d_s[1:] != d_s[:-1]) | (t_s[1:] != t_s[:-1]),
-    ]) & v_s
-    docs_s = d_s
-    terms_s = jnp.where(first, t_s, 0)
-    word_s = jnp.where(first, docs_s // 32, 0).astype(jnp.int32)
-    bit_s = (docs_s % 32).astype(jnp.uint32)
-    contrib = jnp.where(first, jnp.uint32(1) << bit_s, jnp.uint32(0))
+    # Dedupe each document's terms so each (doc, term) contributes one bit
+    # and one df count, regardless of within-doc term repetition: rows are
+    # distinct documents, so a sort within each row suffices (a global
+    # sort of all (doc, term) pairs takes tens of seconds to compile for
+    # a TPU v5e).
+    terms = jnp.sort(jnp.where(new_doc_valid[:, None], new_doc_terms, -1),
+                     axis=1)                                       # (N, M)
+    first = (terms >= 0) & jnp.concatenate(
+        [jnp.ones((n_new, 1), bool), terms[:, 1:] != terms[:, :-1]], axis=1)
+    slots = jnp.clip(doc_slots, 0)[:, None]                         # (N, 1)
+    word_s = jnp.where(first, slots // 32, 0).astype(jnp.int32).reshape(-1)
+    terms_s = jnp.where(first, terms, 0).reshape(-1)
+    bit = (slots % 32).astype(jnp.uint32)
+    contrib = jnp.where(first, jnp.uint32(1) << bit, jnp.uint32(0)).reshape(-1)
 
     # Bitwise-OR scatter.  JAX scatter has add/min/max/mul but no OR; after
-    # (doc, term) dedupe every (word, term, bit) triple is unique and — new
-    # docs being beyond index.n_docs — the target bits are all currently 0,
+    # (doc, term) dedupe every (word, term, bit) triple is unique and — the
+    # target slots being cleared — the target bits are all currently 0,
     # so scatter-add on disjoint bits IS bitwise OR (no carries possible).
     packed = index.packed.at[word_s, terms_s].add(contrib, mode="drop")
 
-    df = index.doc_freq.at[terms_s].add(jnp.where(first, 1, 0), mode="drop")
+    df = index.doc_freq.at[terms_s].add(
+        jnp.where(first, 1, 0).reshape(-1), mode="drop")
     high_water = jnp.max(jnp.where(new_doc_valid,
                                    jnp.clip(doc_slots, 0) + 1, 0))
     n_docs = jnp.maximum(index.n_docs, high_water.astype(jnp.int32))

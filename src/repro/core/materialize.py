@@ -23,7 +23,7 @@ truncate per term):
     dense incidence columns of the row/column tiles; the tiles stream
     through a running per-row top-``k`` merge (`lax.scan`), so the block
     never holds more than one ``(row_tile, col_tile)`` count tile
-    (compiled on TPU, interpret mode elsewhere);
+    (compiled on TPU, interpret mode on the CPU);
   - ``"gemm"`` / ``"popcount"`` (and any registered method) — the
     count-method registry (:mod:`repro.core.query`): one registry call
     per row block produces the (row_tile, V) counts, reduced by one
@@ -66,6 +66,7 @@ import numpy as np
 from repro.core.inverted_index import (
     PackedIndex,
     incidence_dense,
+    transpose_pad,
     unpack_bitmap,
 )
 from repro.core.network import CoocNetwork
@@ -260,14 +261,10 @@ def _resolve_materialize_operands(index, method: str, needs=None):
         ctx = index
         return (ctx, ctx.index, ctx.packed_t(),
                 {name: getattr(ctx, name)() for name in needs})
-    def _packed_t_pad():
-        p = jnp.transpose(index.packed)
-        return jnp.pad(p, ((0, (-p.shape[0]) % 8), (0, (-p.shape[1]) % 128)))
-
     builders = {
         "x_dense": lambda: incidence_dense(index, jnp.bfloat16),
         "packed_t": lambda: index.packed.T,
-        "packed_t_pad": _packed_t_pad,
+        "packed_t_pad": lambda: transpose_pad(index.packed),
     }
     return (None, index, index.packed.T,
             {name: builders[name]() for name in needs})

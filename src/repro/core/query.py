@@ -173,7 +173,7 @@ def _fused_level(index: PackedIndex, masks: jax.Array, terms: jax.Array,
                  ) -> Tuple[jax.Array, jax.Array]:
     """The fused level step: one ``kernels.ops.level_step`` launch over
     the pre-padded transposed postings (compiled Pallas on TPU, the fused
-    XLA fallback elsewhere) — counts, masking, and top-k never round-trip
+    XLA form on the CPU) — counts, masking, and top-k never round-trip
     the (B, V) block."""
     from repro.kernels import ops
     return ops.level_step(masks, operands["packed_t_pad"], terms, valid,

@@ -55,6 +55,7 @@ import numpy as np
 
 from repro.core.inverted_index import (
     PackedIndex,
+    _pack_host,
     grow_capacity,
     grow_vocab,
     incidence_dense,
@@ -62,6 +63,7 @@ from repro.core.inverted_index import (
     pack_docs,
     retire_docs,
     slots_bitmap,
+    transpose_pad,
 )
 from repro.core.query import get_count_method
 
@@ -113,6 +115,13 @@ class QueryContext:
         # re-queries live + cold together (core.storage, core.materialize)
         self._cold = cold_store
         self._cold_seq = 0        # next spill key / cold-tier version
+        if mesh is not None:
+            # the index itself lives sharded on the mesh, as its artifacts
+            # do: left on one device, every sharded query would re-split
+            # the whole bitmap across the mesh
+            index = PackedIndex(self._place(index.packed, ("docs", "terms")),
+                                self._place(index.doc_freq, ("terms",)),
+                                self._place(index.n_docs, ()))
         self._index = index
         self._dtype = dtype
         self.epoch = 0
@@ -173,8 +182,11 @@ class QueryContext:
                   capacity: Optional[int] = None, dtype=jnp.bfloat16,
                   window: Optional[int] = None, mesh=None,
                   cold_store=None) -> "QueryContext":
-        return cls(pack_docs(doc_terms, vocab_size, capacity=capacity),
-                   dtype=dtype, window=window, mesh=mesh,
+        if mesh is None:
+            index = pack_docs(doc_terms, vocab_size, capacity=capacity)
+        else:   # from the host straight to its shards (placed in __init__)
+            index = PackedIndex(*_pack_host(doc_terms, vocab_size, capacity))
+        return cls(index, dtype=dtype, window=window, mesh=mesh,
                    cold_store=cold_store)
 
     @property
@@ -450,6 +462,7 @@ class QueryContext:
         """Dense incidence X (capacity, V), unpacked once per epoch and
         sharded (docs, terms) at build time."""
         if self._x_epoch != self.epoch:
+            self._x_dense = None
             self._x_dense = self._place(
                 incidence_dense(self._index, self._dtype), ("docs", "terms"))
             self._x_epoch = self.epoch
@@ -462,6 +475,7 @@ class QueryContext:
         full-network materialization reads term rows contiguously instead
         of striding over ``packed``'s columns."""
         if self._pt_epoch != self.epoch:
+            self._packed_t = None
             self._packed_t = self._place(jnp.transpose(self._index.packed),
                                          ("terms", "docs"))
             self._pt_epoch = self.epoch
@@ -482,14 +496,19 @@ class QueryContext:
         candidate.
         """
         if self._ptp_epoch != self.epoch:
-            p = jnp.transpose(self._index.packed)
-            v_pad = (-p.shape[0]) % 8
-            w_pad = (-p.shape[1]) % 128
-            if v_pad or w_pad:
-                p = jnp.pad(p, ((0, v_pad), (0, w_pad)))
-            self._packed_t_pad = self._place(p, ("terms", "docs"))
+            self._packed_t_pad = None         # never hold stale + new
+            self._packed_t_pad = self._place(transpose_pad(self._index.packed),
+                                             ("terms", "docs"))
             self._ptp_epoch = self.epoch
         return self._packed_t_pad
+
+    def _drop_artifacts(self) -> None:
+        """Release the derived per-epoch artifacts before the index they
+        derive from is replaced: at CSL size each is as large as the
+        index, and a stale copy held across the rebuild would double
+        it."""
+        self._x_dense = self._packed_t = self._packed_t_pad = None
+        self._x_epoch = self._pt_epoch = self._ptp_epoch = -1
 
     def term_signatures(self, *, num_perm: int = 128, seed: int = 0
                         ) -> jax.Array:
@@ -645,6 +664,7 @@ class QueryContext:
             self._ring_tail = start + n_new
         row_slots = np.zeros((n_rows,), np.int64)
         row_slots[np.flatnonzero(valid_np)] = slots
+        self._drop_artifacts()
         self._index = ingest_at(self._index, new_doc_terms, new_doc_valid,
                                 jnp.asarray(row_slots, jnp.int32))
         if n_new > 0:

@@ -24,11 +24,12 @@ Tie order is exact ``lax.top_k`` order (lower index wins) by the running-
 merge argument of ``materialize._topk_row_block``: running candidates come
 from strictly earlier column tiles (lower global ids) and are already
 sorted lower-id-first within equal weights, the new tile's columns are laid
-out in id order after them, and the per-round ``argmax`` extraction picks
+out in id order after them, and the per-round first-maximum extraction picks
 the FIRST maximum slot.
 
-``level_step_topk_xla`` is the bit-exact compiled fallback (the default off
-TPU — interpret-mode Pallas is a correctness path, not a serving path).
+``level_step_topk_xla`` is the bit-exact XLA form, the default on the CPU
+(interpret-mode Pallas is a correctness path there, not a serving path).
+On a TPU the compiled kernel always runs (``kernels.ops``).
 """
 from __future__ import annotations
 
@@ -57,15 +58,19 @@ def _masked_counts(counts: jax.Array, cols: jax.Array, terms: jax.Array,
 
 def _topk_rounds(cand_w: jax.Array, cand_i: jax.Array, k: int):
     """Exact top-k by k rounds of first-maximum extraction (no lax.top_k
-    inside the kernel).  argmax ties resolve to the first slot == the
-    lowest candidate index under the merge layout — lax.top_k order."""
+    inside the kernel).  Each round takes the row max, then the lowest
+    slot holding it (a min over slot ids: Mosaic lowers argmax for float32
+    only) == the lowest candidate index under the merge layout —
+    lax.top_k order."""
     n_cand = cand_w.shape[1]
     slot = jax.lax.broadcasted_iota(jnp.int32, (1, n_cand), 1)
     ws, ids = [], []
     for _ in range(k):
-        sel = jnp.argmax(cand_w, axis=1).astype(jnp.int32)   # first max
-        hit = slot == sel[:, None]
-        ws.append(jnp.max(cand_w, axis=1))
+        w_max = jnp.max(cand_w, axis=1, keepdims=True)
+        sel = jnp.min(jnp.where(cand_w == w_max, slot, n_cand), axis=1,
+                      keepdims=True)                         # first max
+        hit = slot == sel
+        ws.append(w_max[:, 0])
         ids.append(jnp.sum(jnp.where(hit, cand_i, 0), axis=1))
         cand_w = jnp.where(hit, jnp.int32(-3), cand_w)       # pop the slot
     return jnp.stack(ws, axis=1), jnp.stack(ids, axis=1)
@@ -152,7 +157,7 @@ def level_step_pallas(masks: jax.Array, packed_t_pad: jax.Array,
 def level_step_topk_xla(masks: jax.Array, packed_t_pad: jax.Array,
                         terms: jax.Array, valid: jax.Array,
                         visited: jax.Array, *, v: int, k: int):
-    """Bit-exact compiled fallback (same operands as the Pallas kernel,
+    """Bit-exact XLA form for the CPU (same operands as the Pallas kernel,
     minus the tile-shape constraints): one popcount pass over the padded
     postings, the fused masks, one chunked top-k.  Padding columns sit at
     -2 so k <= v outputs are always real columns in lax.top_k order.

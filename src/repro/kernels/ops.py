@@ -3,8 +3,9 @@
 ``backend``:
   * "pallas"     — compiled Pallas (the TPU target)
   * "interpret"  — Pallas interpret mode (CPU correctness validation)
-  * "xla"        — the pure-jnp oracle from ref.py (CPU-fast fallback)
-  * None         — pick: pallas on TPU, xla elsewhere.
+  * "xla"        — the pure-jnp oracle from ref.py (CPU only)
+  * None         — pick: pallas on TPU, xla on the CPU; any other
+                   platform raises (nothing stands in for the chip).
 
 All wrappers pad to the kernels' tile multiples and slice the result back,
 so callers never see shape constraints.
@@ -25,20 +26,37 @@ from repro.kernels.level_step import level_step_pallas, level_step_topk_xla
 from repro.kernels.postings import postings_counts_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _platform() -> str:
+    """The platform the kernels are traced for (the default backend)."""
+    return jax.default_backend()
+
+
+def _pick(on_tpu: str, on_cpu: str) -> str:
+    p = _platform()
+    if p == "tpu":
+        return on_tpu
+    if p == "cpu":
+        return on_cpu
+    raise RuntimeError(
+        f"no kernel backend for platform {p!r}: compiled Pallas needs a "
+        "TPU, and the XLA reference and interpret mode run on the CPU only")
 
 
 def _resolve(backend: Optional[str]) -> str:
-    if backend is not None:
-        return backend
-    return "pallas" if _on_tpu() else "xla"
+    if backend is None:
+        return _pick("pallas", "xla")
+    if backend in ("xla", "interpret") and _platform() != "cpu":
+        raise RuntimeError(
+            f"backend {backend!r} runs on the CPU only; on {_platform()!r} "
+            "the kernels run compiled (backend='pallas' or None)")
+    return backend
 
 
 def pallas_backend() -> str:
     """Backend string that always exercises the Pallas kernel: compiled on
-    TPU, interpret mode elsewhere (CPU correctness/serving fallback)."""
-    return "pallas" if _on_tpu() else "interpret"
+    TPU, interpret mode on the CPU (correctness runs); any other platform
+    is an error."""
+    return _pick("pallas", "interpret")
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
@@ -119,7 +137,6 @@ def cooccur_counts_sharded(x_l: jax.Array, x_r: jax.Array, *, mesh,
     wrapper pads to tile multiples.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.launch.sharding import shard_map_compat
     n_data = mesh.shape.get("data", 1)
     n_model = mesh.shape.get("model", 1)
     if n_data > 1 and n_model > 1:
@@ -135,9 +152,9 @@ def cooccur_counts_sharded(x_l: jax.Array, x_r: jax.Array, *, mesh,
                                bk=bk)
             return jax.lax.all_gather(c, "model", axis=1, tiled=True)
 
-        out = shard_map_compat(local, mesh,
-                               in_specs=(P(), P(None, "model")),
-                               out_specs=P(None, None))(x_l, xr)
+        out = jax.shard_map(local, mesh=mesh,
+                            in_specs=(P(), P(None, "model")),
+                            out_specs=P(None, None), check_vma=False)(x_l, xr)
         return out[:, :vr]
 
     # doc-sharded contraction rows + psum merge
@@ -148,9 +165,9 @@ def cooccur_counts_sharded(x_l: jax.Array, x_r: jax.Array, *, mesh,
         c = cooccur_counts(x_l_l, x_r_l, backend=backend, bm=bm, bn=bn, bk=bk)
         return jax.lax.psum(c, "data")
 
-    return shard_map_compat(local, mesh,
-                            in_specs=(P("data", None), P("data", None)),
-                            out_specs=P(None, None))(xl, xr)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P("data", None), P("data", None)),
+                         out_specs=P(None, None), check_vma=False)(xl, xr)
 
 
 # -- postings popcount -------------------------------------------------------
@@ -163,8 +180,9 @@ def postings_counts(masks: jax.Array, packed: jax.Array, *,
     if b == "xla":
         return ref.postings_counts_ref(masks, packed)
     nb, v = masks.shape[0], packed.shape[1]
+    bw = min(bw, packed.shape[0])     # a short index is one full block
     m = _pad_to(_pad_to(masks, 0, bb), 1, bw)
-    p = _pad_to(_pad_to(packed, 0, bw), 1, bv)
+    p = _pad_to(packed, 1, bv)        # the W axis is never padded
     out = postings_counts_pallas(m, p, bb=bb, bv=bv, bw=bw,
                                  interpret=(b == "interpret"))
     return out[:nb, :v]
@@ -209,7 +227,7 @@ def level_step(masks: jax.Array, packed_t_pad: jax.Array, terms: jax.Array,
            else jnp.zeros(visited.shape, jnp.int32))
     vld = valid.astype(jnp.int32)
     if b == "xla":
-        # the compiled-XLA fallback has no tile-shape constraint: slice
+        # the XLA form (CPU only) has no tile-shape constraint: slice
         # the artifact back to the true (v, W) so the popcount touches
         # zero padding work (a static slice of the cached artifact, not a
         # per-call pad — shapes stay fixed across submits within an epoch)

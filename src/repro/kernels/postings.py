@@ -9,8 +9,10 @@ document frequencies
 This is the memory-bound streaming op of the optimized algorithm — one
 pass over ``packed`` per BFS level.  int32 accumulation, exact for any D.
 
-Tiling: grid (B/bb, V/bv, W/bw); W innermost, accumulating into the
-resident (bb, bv) int32 output block.  VPU op (AND + popcount + reduce) —
+Tiling: grid (B/bb, V/bv, ceil(W/bw)); W innermost, accumulating into
+the resident (bb, bv) int32 output block.  ``packed`` is never padded: the
+last W block may run past the index, and the masks (padded with zero
+words, O(B·W)) AND whatever it reads there to zero.  VPU op (AND + popcount + reduce) —
 no MXU involvement, so the roofline term is pure HBM bandwidth.
 """
 from __future__ import annotations
@@ -37,17 +39,18 @@ def _postings_kernel(masks_ref, packed_ref, out_ref):
 def postings_counts_pallas(masks: jax.Array, packed: jax.Array, *, bb: int = 8,
                            bv: int = 512, bw: int = 256,
                            interpret: bool = False) -> jax.Array:
-    """counts (B, V) int32 from masks (B, W) and packed (W, V), both uint32.
+    """counts (B, V) int32 from masks (B, W_pad) and packed (W, V), both
+    uint32, with W_pad = ceil(W / bw) * bw and mask words >= W all zero.
 
-    Requires divisibility (ops.py pads).  VMEM per step:
+    Requires B % bb == V % bv == 0 (ops.py pads).  VMEM per step:
     bb*bw*4 + bw*bv*4 + bb*bw*bv*4 (the AND intermediate) — with
     (8, 512, 256) the intermediate is 4 MB; fits VMEM with headroom.
     """
-    b, w = masks.shape
-    w2, v = packed.shape
-    assert w == w2, (w, w2)
-    assert b % bb == 0 and v % bv == 0 and w % bw == 0, (b, v, w, bb, bv, bw)
-    grid = (b // bb, v // bv, w // bw)
+    b, wp = masks.shape
+    w, v = packed.shape
+    assert wp == -(-w // bw) * bw, (wp, w, bw)
+    assert b % bb == 0 and v % bv == 0, (b, v, bb, bv)
+    grid = (b // bb, v // bv, wp // bw)
     return pl.pallas_call(
         _postings_kernel,
         grid=grid,

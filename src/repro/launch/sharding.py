@@ -60,11 +60,8 @@ def axis_rules(mesh: Mesh, rules: Optional[Dict[str, Tuple[str, ...]]] = None):
     if rules:
         merged.update(rules)
     tok = _ACTIVE.set(_Ctx(mesh, merged))
-    # jax.sharding.set_mesh is the modern global-mesh setter; older jax
-    # versions use the Mesh object itself as the resource-env context.
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
     try:
-        with (set_mesh(mesh) if set_mesh is not None else mesh):
+        with jax.sharding.set_mesh(mesh):
             yield
     finally:
         _ACTIVE.reset(tok)
@@ -160,21 +157,3 @@ def sharding_tree(specs_logical, shapes):
 def active_mesh() -> Optional[Mesh]:
     ctx = _ACTIVE.get()
     return ctx.mesh if ctx else None
-
-
-def shard_map_compat(fn, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: the experimental module moved,
-    and the replication-check kwarg was renamed (``check_rep`` ->
-    ``check_vma``).  The check is disabled — the popcount/all_gather
-    compositions the query layer shard_maps don't all carry rep rules.
-    The one shim for every sharded execution site (``core.distributed``,
-    ``kernels.ops``)."""
-    import inspect
-    try:  # pragma: no cover - moved out of experimental in newer jax
-        from jax.shard_map import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-    kw = ("check_rep" if "check_rep"
-          in inspect.signature(shard_map).parameters else "check_vma")
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{kw: False})

@@ -225,10 +225,14 @@ class CoocEngine:
         if fn is not None:
             self._executors.move_to_end(exec_key)
             return fn
-        fn = jax.jit(functools.partial(
+        step = functools.partial(
             bfs_construct_batch, depth=key.depth, topk=key.topk,
             beam=key.beam, dedup=key.dedup, method=key.method,
-            mesh=self.ctx.mesh))
+            mesh=self.ctx.mesh)
+        # the executable's name in compile events and profiler traces
+        step.__name__ = (f"cooc_plan_{key.method}_d{key.depth}_k{key.topk}"
+                         f"_b{key.beam}")
+        fn = jax.jit(step)
         self._executors[exec_key] = fn
         if self.compile_budget is not None:
             while len(self._executors) > self.compile_budget:

@@ -26,7 +26,6 @@ from typing import Any, Callable, Dict, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def quantize_int8(e: jax.Array, scale: jax.Array) -> jax.Array:
@@ -80,12 +79,12 @@ def make_ddp_train_step(mesh: Mesh, data_axes: Tuple[str, ...],
 
         batch_spec = jax.tree.map(lambda _: P(data_axes), batch)
         rep = lambda t: jax.tree.map(lambda _: P(), t)
-        return shard_map(
+        return jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(rep(params), rep(opt_state), rep(residual), batch_spec),
             out_specs=(rep(params), rep(opt_state), rep(residual),
                        {"grad_norm": P(), "lr": P()}),
-            check_rep=False,
+            check_vma=False,
         )(params, opt_state, residual, batch)
 
     return step

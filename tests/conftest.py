@@ -1,52 +1,5 @@
-import functools
-import sys
-import types
-
 import numpy as np
 import pytest
-
-try:  # pragma: no cover - prefer the real library when present
-    import hypothesis  # noqa: F401
-except ImportError:
-    # Minimal stand-in for the slice of hypothesis this suite uses
-    # (@given + @settings + st.integers): deterministic pseudo-random
-    # example draws so the property tests still execute where the real
-    # package isn't installed (the container has no network access).
-    def _integers(lo, hi):
-        def draw(rng):
-            return int(rng.integers(lo, hi + 1))
-        return draw
-
-    def _given(*strats):
-        def deco(fn):
-            n = getattr(fn, "_max_examples", 10)
-
-            @functools.wraps(fn)
-            def wrapper(*args, **kwargs):
-                rng = np.random.default_rng(
-                    int.from_bytes(fn.__qualname__.encode(), "little") % (1 << 32))
-                for _ in range(n):
-                    fn(*args, *(s(rng) for s in strats), **kwargs)
-            # pytest introspects __wrapped__ for the signature and would
-            # treat the drawn parameters as fixtures; hide the original.
-            del wrapper.__wrapped__
-            return wrapper
-        return deco
-
-    def _settings(max_examples=10, deadline=None, **_kw):
-        def deco(fn):
-            fn._max_examples = max_examples
-            return fn
-        return deco
-
-    _hyp = types.ModuleType("hypothesis")
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.integers = _integers
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.strategies = _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
 
 
 @pytest.fixture(scope="session")

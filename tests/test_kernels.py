@@ -132,11 +132,18 @@ def test_postings_pallas_small_tiles_non_divisible():
         np.testing.assert_array_equal(np.asarray(out), want)
 
 
-def test_pallas_backend_resolution():
-    """pallas_backend(): compiled on TPU, interpret elsewhere — the
-    method='pallas' dispatch always exercises the kernel."""
-    want = "pallas" if jax.default_backend() == "tpu" else "interpret"
-    assert ops.pallas_backend() == want
+@pytest.mark.parametrize("platform,want", [
+    ("tpu", "pallas"), ("cpu", "interpret"), ("gpu", None)])
+def test_pallas_backend_resolution(monkeypatch, platform, want):
+    """pallas_backend(): compiled on TPU, interpret mode on the CPU — the
+    method='pallas' dispatch always exercises the kernel — and an error
+    on any other platform."""
+    monkeypatch.setattr(ops, "_platform", lambda: platform)
+    if want is None:
+        with pytest.raises(RuntimeError, match=platform):
+            ops.pallas_backend()
+    else:
+        assert ops.pallas_backend() == want
 
 
 def test_postings_counts_sparse_bitmaps():
@@ -204,6 +211,28 @@ def test_level_step_matches_oracle_chain(b, v, w, k, dedup, backend):
                                   v=v, k=k, dedup=dedup, backend=backend)
     np.testing.assert_array_equal(np.asarray(want_w), np.asarray(got_w))
     np.testing.assert_array_equal(np.asarray(want_i), np.asarray(got_i))
+
+
+def test_level_step_kernel_under_vmap_matches_oracle_chain():
+    """The serving engine vmaps the level step over a batch of queries
+    sharing one artifact: the batched kernel (extra grid axis, revisited
+    outputs, VMEM scratch) must still equal the per-query oracle, here
+    with several V and W tiles per query."""
+    q, b, v, w, k = 3, 5, 300, 200, 7
+    packed, _, _, _, _, pt = _level_inputs(b, v, w, 11)
+    rng = np.random.default_rng(12)
+    masks = jnp.asarray(rng.integers(0, 1 << 32, (q, b, w), dtype=np.uint32))
+    terms = jnp.asarray(rng.integers(-1, v, (q, b)), jnp.int32)
+    valid = jnp.asarray(rng.integers(0, 2, (q, b)), bool)
+    visited = jnp.asarray(rng.integers(0, 2, (q, v)), bool)
+    step = jax.vmap(lambda m, t, va, vi: ops.level_step(
+        m, pt, t, va, vi, v=v, k=k, backend="interpret"))
+    got_w, got_i = step(masks, terms, valid, visited)
+    for j in range(q):
+        want_w, want_i = _level_oracle(packed, masks[j], terms[j], valid[j],
+                                       visited[j], k=k, dedup=True)
+        np.testing.assert_array_equal(np.asarray(want_w), np.asarray(got_w[j]))
+        np.testing.assert_array_equal(np.asarray(want_i), np.asarray(got_i[j]))
 
 
 def test_level_step_refuses_unpadded_artifact():
@@ -331,3 +360,22 @@ def test_default_backend_is_xla_on_cpu():
     out = ops.cooccur_gemm(jnp.asarray(x), jnp.asarray(x))   # backend=None
     want = ref.cooccur_gemm_ref(jnp.asarray(x), jnp.asarray(x))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+@pytest.mark.parametrize("platform,backend,want", [
+    ("tpu", None, "pallas"),
+    ("tpu", "pallas", "pallas"),
+    ("cpu", None, "xla"),
+    ("cpu", "interpret", "interpret"),
+    ("cpu", "pallas", "pallas"),
+    ("tpu", "xla", None),            # the reference never stands in for the chip
+    ("tpu", "interpret", None),
+    ("gpu", None, None),             # no kernel backend: an error, not a fallback
+])
+def test_backend_choice_per_platform(monkeypatch, platform, backend, want):
+    monkeypatch.setattr(ops, "_platform", lambda: platform)
+    if want is None:
+        with pytest.raises(RuntimeError, match=platform):
+            ops._resolve(backend)
+    else:
+        assert ops._resolve(backend) == want

@@ -167,7 +167,6 @@ class TestCompression:
         """n=1 shard: quantisation error is carried in the residual, so two
         steps of the same gradient reconstruct it to within int8 precision."""
         mesh = make_mesh((1,), ("data",))
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         g = {"w": jnp.asarray(np.random.default_rng(0).standard_normal(64),
@@ -177,8 +176,8 @@ class TestCompression:
         def f(g, r):
             return compressed_psum(g, r, ("data",), 1)
 
-        out, res = shard_map(f, mesh=mesh, in_specs=(P(), P()),
-                             out_specs=(P(), P()), check_rep=False)(g, r)
+        out, res = jax.shard_map(f, mesh=mesh, in_specs=(P(), P()),
+                                 out_specs=(P(), P()), check_vma=False)(g, r)
         err1 = np.abs(np.asarray(out["w"]) - np.asarray(g["w"])).max()
         scale = np.abs(np.asarray(g["w"])).max() / 127
         assert err1 <= scale + 1e-6

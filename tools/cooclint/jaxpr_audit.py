@@ -34,7 +34,6 @@ host devices via ``XLA_FLAGS``).
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 FORBIDDEN_SUBSTRINGS = ("callback",)
@@ -65,17 +64,8 @@ class AuditResult:
 # ---------------------------------------------------------------------------
 
 
-def _jaxpr_types():
-    try:
-        from jax.extend import core as jex_core
-        return jex_core.Jaxpr, jex_core.ClosedJaxpr
-    except (ImportError, AttributeError):
-        from jax import core as jax_core
-        return jax_core.Jaxpr, jax_core.ClosedJaxpr
-
-
 def _sub_jaxprs(value) -> Iterable:
-    Jaxpr, ClosedJaxpr = _jaxpr_types()
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, Jaxpr):
@@ -132,17 +122,32 @@ def trace_and_audit(fn: Callable, args: Tuple, entry: str = "<fn>",
                     kwargs: Optional[dict] = None) -> List[str]:
     """``make_jaxpr`` over abstract args, then :func:`audit_jaxpr`.
 
-    A trace-time concretization error (``jax.device_get``, ``.item()``,
-    python ``float()`` on a tracer) IS a sync-point finding, not an
-    auditor crash.
+    Every ``kwargs`` entry made of ``ShapeDtypeStruct`` leaves (a cached
+    operand such as ``operands={"x_dense": ...}``) is traced like ``args``;
+    the rest (depths, method names, meshes) stay static.  A trace-time
+    concretization error (``jax.device_get``, ``.item()``, python
+    ``float()`` on a tracer) IS a sync-point finding, not an auditor crash.
     """
     import jax
     import jax.errors
     sync_errors = (jax.errors.ConcretizationTypeError,
                    jax.errors.TracerArrayConversionError,
                    jax.errors.TracerIntegerConversionError)
+
+    def abstract(v) -> bool:
+        leaves = jax.tree_util.tree_leaves(v)
+        return bool(leaves) and all(isinstance(x, jax.ShapeDtypeStruct)
+                                    for x in leaves)
+
+    kwargs = kwargs or {}
+    traced = {k: v for k, v in kwargs.items() if abstract(v)}
+    static = {k: v for k, v in kwargs.items() if k not in traced}
+
+    def call(args, traced):
+        return fn(*args, **traced, **static)
+
     try:
-        closed = jax.make_jaxpr(functools.partial(fn, **(kwargs or {})))(*args)
+        closed = jax.make_jaxpr(call)(args, traced)
     except sync_errors as e:
         first = str(e).strip().splitlines()[0]
         return [f"{entry}: trace-time host sync "
