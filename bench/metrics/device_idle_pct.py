@@ -1,0 +1,10 @@
+"""Device: the share of the window in which no operation ran on the
+chip, 1 - (union of the device's op intervals) / window, from the
+trace."""
+
+
+def read(run):
+    t = run.trace
+    if t is None or t.window_s <= 0:
+        return None
+    return 100.0 * (1.0 - t.busy_s / t.window_s)
