@@ -344,12 +344,15 @@ class QueryResult:
     epoch   — the index epoch answered against (which ingests are visible);
     latency_ms / batch_occupancy — serving stats for THIS query (0 / 1 for
     one-shot construction outside an engine).
+    queue_ms — set by a CoocServer: from enqueue until the query's batch
+    left the server's queue (the batcher's linger included); 0 elsewhere.
     """
     network: CoocNetwork
     spec: QuerySpec
     epoch: int = 0
     latency_ms: float = 0.0
     batch_occupancy: int = 1
+    queue_ms: float = 0.0
     _edges: Optional[Dict[Tuple[int, int], int]] = dataclasses.field(
         default=None, repr=False, compare=False)
 

@@ -45,6 +45,7 @@ epoch is a new array — no retrace, no stale constants baked into traces.
 """
 from __future__ import annotations
 
+import contextlib
 from collections import deque
 from collections.abc import Mapping
 from typing import Deque, Dict, Optional, Sequence, Tuple, Union
@@ -66,6 +67,7 @@ from repro.core.inverted_index import (
     transpose_pad,
 )
 from repro.core.query import get_count_method
+from repro.core.spans import SpanLog
 
 
 class _CountMethodsView(Mapping):
@@ -125,6 +127,10 @@ class QueryContext:
         self._index = index
         self._dtype = dtype
         self.epoch = 0
+        #: spans of the engine steps, ingests and artifact rebuilds served
+        #: from this context (``cooc.engine.step``, ``cooc.index.*``)
+        self.spans = SpanLog()
+        self._ingesting = False
         self._x_dense: Optional[jax.Array] = None
         self._x_epoch = -1
         self.unpack_count = 0   # monitoring: dense rebuilds == ingest epochs
@@ -451,20 +457,30 @@ class QueryContext:
                            f"defined scopes: {list(self.scope_names())}")
         ent = self._scope_dev.get(name)
         if ent is None or ent[0] != self.epoch:
-            arr = jnp.asarray(self._scope_host(name))
+            with self._rebuild("scope"):
+                arr = jnp.asarray(self._scope_host(name))
             self._scope_dev[name] = (self.epoch, arr)
             ent = self._scope_dev[name]
         return ent[1]
 
     # -- cached artifacts ---------------------------------------------------
 
+    def _rebuild(self, artifact: str):
+        """The ``cooc.index.rebuild`` span of one per-epoch artifact
+        (re)built on a miss, counted under
+        ``artifact_rebuilds_total{artifact=...}``."""
+        self.spans.count("artifact_rebuilds_total", artifact=artifact)
+        return self.spans.span("cooc.index.rebuild")
+
     def x_dense(self) -> jax.Array:
         """Dense incidence X (capacity, V), unpacked once per epoch and
         sharded (docs, terms) at build time."""
         if self._x_epoch != self.epoch:
             self._x_dense = None
-            self._x_dense = self._place(
-                incidence_dense(self._index, self._dtype), ("docs", "terms"))
+            with self._rebuild("x_dense"):
+                self._x_dense = self._place(
+                    incidence_dense(self._index, self._dtype),
+                    ("docs", "terms"))
             self._x_epoch = self.epoch
             self.unpack_count += 1
         return self._x_dense
@@ -476,8 +492,9 @@ class QueryContext:
         of striding over ``packed``'s columns."""
         if self._pt_epoch != self.epoch:
             self._packed_t = None
-            self._packed_t = self._place(jnp.transpose(self._index.packed),
-                                         ("terms", "docs"))
+            with self._rebuild("packed_t"):
+                self._packed_t = self._place(
+                    jnp.transpose(self._index.packed), ("terms", "docs"))
             self._pt_epoch = self.epoch
         return self._packed_t
 
@@ -497,8 +514,9 @@ class QueryContext:
         """
         if self._ptp_epoch != self.epoch:
             self._packed_t_pad = None         # never hold stale + new
-            self._packed_t_pad = self._place(transpose_pad(self._index.packed),
-                                             ("terms", "docs"))
+            with self._rebuild("packed_t_pad"):
+                self._packed_t_pad = self._place(
+                    transpose_pad(self._index.packed), ("terms", "docs"))
             self._ptp_epoch = self.epoch
         return self._packed_t_pad
 
@@ -604,7 +622,8 @@ class QueryContext:
                on_overflow: str = "raise",
                scope: Union[str, Sequence[str], None] = None) -> np.ndarray:
         """Ingest a block of documents; returns the slot ids assigned to
-        the block's valid rows (in row order).
+        the block's valid rows (in row order).  Timed as one
+        ``cooc.index.ingest`` span.
 
         Append mode (no window): host-side capacity check BEFORE the jitted
         scatter (the device scatter clamps out-of-range writes with
@@ -620,6 +639,27 @@ class QueryContext:
 
         ``scope`` tags the new block into the named scope bitmap(s).
         """
+        with self._ingest_span():
+            return self._ingest(new_doc_terms, new_doc_valid,
+                                on_overflow=on_overflow, scope=scope)
+
+    @contextlib.contextmanager
+    def _ingest_span(self):
+        """``cooc.index.ingest`` around the outermost of
+        :meth:`ingest_docs` / :meth:`ingest`: one span per ingest."""
+        if self._ingesting:
+            yield
+            return
+        self._ingesting = True
+        try:
+            with self.spans.span("cooc.index.ingest"):
+                yield
+        finally:
+            self._ingesting = False
+
+    def _ingest(self, new_doc_terms: jax.Array, new_doc_valid: jax.Array, *,
+                on_overflow: str,
+                scope: Union[str, Sequence[str], None]) -> np.ndarray:
         valid_np = np.asarray(new_doc_valid).astype(bool)
         n_new = int(valid_np.sum())
         n_rows = valid_np.shape[0]
@@ -723,21 +763,28 @@ class QueryContext:
         window: enters (or resizes) sliding-window mode before this ingest
         — equivalent to :meth:`set_window` then :meth:`ingest`.
         scope: tag the new docs into the named scope bitmap(s).
+
+        The whole call, padding included, is one ``cooc.index.ingest``
+        span.
         """
-        if window is not None:
-            self.set_window(window)
-        doc_terms = [list(t) for t in doc_terms]
-        over = [(i, len(t)) for i, t in enumerate(doc_terms) if len(t) > max_len]
-        if over and on_long != "truncate":
-            i0, l0 = over[0]
-            raise ValueError(
-                f"{len(over)} document(s) exceed max_len={max_len} (first: "
-                f"doc {i0} with {l0} terms); term ids past max_len would be "
-                f"silently dropped — raise max_len or pass on_long='truncate'")
-        n = len(doc_terms)
-        ids = np.full((n, max_len), -1, np.int32)
-        for i, t in enumerate(doc_terms):
-            t = t[:max_len]
-            ids[i, :len(t)] = t
-        return self.ingest(jnp.asarray(ids), jnp.asarray(np.ones((n,), bool)),
-                           on_overflow=on_overflow, scope=scope)
+        with self._ingest_span():
+            if window is not None:
+                self.set_window(window)
+            doc_terms = [list(t) for t in doc_terms]
+            over = [(i, len(t)) for i, t in enumerate(doc_terms)
+                    if len(t) > max_len]
+            if over and on_long != "truncate":
+                i0, l0 = over[0]
+                raise ValueError(
+                    f"{len(over)} document(s) exceed max_len={max_len} "
+                    f"(first: doc {i0} with {l0} terms); term ids past "
+                    f"max_len would be silently dropped — raise max_len or "
+                    f"pass on_long='truncate'")
+            n = len(doc_terms)
+            ids = np.full((n, max_len), -1, np.int32)
+            for i, t in enumerate(doc_terms):
+                t = t[:max_len]
+                ids[i, :len(t)] = t
+            return self.ingest(jnp.asarray(ids),
+                               jnp.asarray(np.ones((n,), bool)),
+                               on_overflow=on_overflow, scope=scope)

@@ -287,63 +287,78 @@ class CoocEngine:
         """Serve one micro-batch: admit up to q_batch queued queries of the
         head-of-queue PLAN, run its cached jitted executable once,
         distribute QueryResults.  Returns #requests resolved (served, or
-        failed onto their futures)."""
+        failed onto their futures).
+
+        Timed as one ``cooc.engine.step`` span on the context's log, tiled
+        by four children: ``cooc.step.prepare`` (plan, scope bitmap, seeds,
+        operands), ``cooc.step.dispatch`` (the executable's call; a compile
+        lands here), ``cooc.step.device`` (the host blocked on the device)
+        and ``cooc.step.fetch`` (results to the host)."""
         if not self.queue:
             return 0
-        key = self.queue[0].spec.plan_key
-        kwargs = {}
-        if key.scope is not None:
-            # resolved BEFORE the queue is mutated; grouping by plan key
-            # guarantees the whole batch shares this one bitmap.  A scope
-            # dropped between submit and step poisons exactly that plan's
-            # requests — they fail onto their futures and leave the queue,
-            # so one bad scope can never wedge the engine.
-            try:
-                kwargs["scope_mask"] = self.ctx.scope(key.scope)
-            except KeyError as e:
-                poisoned = [r for r in self.queue if r.spec.plan_key == key]
-                self.queue = [r for r in self.queue
-                              if r.spec.plan_key != key]
-                return self._fail_requests(poisoned, e)
-        else:
-            # unscoped plans pass the context's cached all-ones bitmap —
-            # the identity under AND — so they trace with the same operand
-            # signature as scoped plans and share their executable
-            kwargs["scope_mask"] = self.ctx.full_mask()
-        admitted: List[CoocRequest] = []
-        rest: List[CoocRequest] = []
-        for req in self.queue:
-            if req.spec.plan_key == key and len(admitted) < self.q_batch:
-                admitted.append(req)
-            else:
-                rest.append(req)
-        self.queue = rest
+        spans = self.ctx.spans
+        with spans.span("cooc.engine.step"):
+            with spans.span("cooc.step.prepare"):
+                key = self.queue[0].spec.plan_key
+                kwargs = {}
+                if key.scope is not None:
+                    # resolved BEFORE the queue is mutated; grouping by plan
+                    # key guarantees the whole batch shares this one bitmap.
+                    # A scope dropped between submit and step poisons
+                    # exactly that plan's requests — they fail onto their
+                    # futures and leave the queue, so one bad scope can
+                    # never wedge the engine.
+                    try:
+                        kwargs["scope_mask"] = self.ctx.scope(key.scope)
+                    except KeyError as e:
+                        poisoned = [r for r in self.queue
+                                    if r.spec.plan_key == key]
+                        self.queue = [r for r in self.queue
+                                      if r.spec.plan_key != key]
+                        return self._fail_requests(poisoned, e)
+                else:
+                    # unscoped plans pass the context's cached all-ones
+                    # bitmap — the identity under AND — so they trace with
+                    # the same operand signature as scoped plans and share
+                    # their executable
+                    kwargs["scope_mask"] = self.ctx.full_mask()
+                admitted: List[CoocRequest] = []
+                rest: List[CoocRequest] = []
+                for req in self.queue:
+                    if (req.spec.plan_key == key
+                            and len(admitted) < self.q_batch):
+                        admitted.append(req)
+                    else:
+                        rest.append(req)
+                self.queue = rest
 
-        seeds = np.full((self.q_batch, key.beam), -1, np.int32)
-        for i, req in enumerate(admitted):
-            seeds[i] = req.spec.seed_row()
-        operands = self.ctx.operands(key.method)
-        net = self._executor(key)(self.ctx.index, jnp.asarray(seeds),
-                                  operands=operands, **kwargs)
-        jax.block_until_ready(net.src)
-
-        src = np.asarray(net.src).reshape(self.q_batch, -1)
-        dst = np.asarray(net.dst).reshape(self.q_batch, -1)
-        w = np.asarray(net.weight).reshape(self.q_batch, -1)
-        valid = np.asarray(net.valid).reshape(self.q_batch, -1)
-        t_done = time.perf_counter()
-        occ = len(admitted)
-        self.batch_occupancy.append(occ)
-        self.batches_total += 1
-        for i, req in enumerate(admitted):
-            req.t_done = t_done
-            req.result = QueryResult(
-                network=CoocNetwork(src[i], dst[i], w[i], valid[i]),
-                spec=req.spec, epoch=self.ctx.epoch,
-                latency_ms=req.latency_ms, batch_occupancy=occ)
-            self.latencies_ms.append(req.latency_ms)
-            self.finished.append(req)
-            self.served_total += 1
+                seeds = np.full((self.q_batch, key.beam), -1, np.int32)
+                for i, req in enumerate(admitted):
+                    seeds[i] = req.spec.seed_row()
+                operands = self.ctx.operands(key.method)
+            with spans.span("cooc.step.dispatch"):
+                net = self._executor(key)(self.ctx.index, jnp.asarray(seeds),
+                                          operands=operands, **kwargs)
+            with spans.span("cooc.step.device"):
+                jax.block_until_ready(net.src)
+            with spans.span("cooc.step.fetch"):
+                src = np.asarray(net.src).reshape(self.q_batch, -1)
+                dst = np.asarray(net.dst).reshape(self.q_batch, -1)
+                w = np.asarray(net.weight).reshape(self.q_batch, -1)
+                valid = np.asarray(net.valid).reshape(self.q_batch, -1)
+                t_done = time.perf_counter()
+                occ = len(admitted)
+                self.batch_occupancy.append(occ)
+                self.batches_total += 1
+                for i, req in enumerate(admitted):
+                    req.t_done = t_done
+                    req.result = QueryResult(
+                        network=CoocNetwork(src[i], dst[i], w[i], valid[i]),
+                        spec=req.spec, epoch=self.ctx.epoch,
+                        latency_ms=req.latency_ms, batch_occupancy=occ)
+                    self.latencies_ms.append(req.latency_ms)
+                    self.finished.append(req)
+                    self.served_total += 1
         return occ
 
     def _fail_requests(self, reqs: List[CoocRequest], error: Exception) -> int:

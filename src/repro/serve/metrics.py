@@ -16,7 +16,9 @@ State is bounded by construction: histograms are fixed-size rings
 ints.  :meth:`ServerMetrics.snapshot` returns a frozen
 :class:`MetricsSnapshot`; :meth:`ServerMetrics.render` emits the same data
 as a plaintext exposition dump (``name{label="value"} number`` lines, one
-metric per line) for scraping or eyeballing.
+metric per line) for scraping or eyeballing.  The snapshot also carries
+the serving path's spans and counters (:mod:`repro.core.spans`): per span
+name the count, total and max over its ring, and each counter.
 """
 from __future__ import annotations
 
@@ -25,6 +27,8 @@ from collections import deque
 from typing import Deque, Dict, Iterable, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.core.spans import SpanSummary
 
 #: the serving stack's canonical quantile set (fractions of 100).
 SERVING_QUANTILES: Tuple[float, ...] = (50.0, 95.0, 99.0, 99.9)
@@ -111,7 +115,8 @@ class MetricsSnapshot:
     last ``window`` served requests (all tenants pooled); queue depths are
     gauges (current / high-water).  ``compiled_plans`` / ``plan_evictions``
     mirror the engines' bounded executor caches — the compile-budget
-    acceptance metric.
+    acceptance metric.  ``spans`` summarises each span name's ring and
+    ``counters`` holds the span logs' counters (``name{label="v"}`` keys).
     """
     tenants: Dict[str, TenantSnapshot]
     latency: QuantileSummary
@@ -125,6 +130,8 @@ class MetricsSnapshot:
     ingested_docs_total: int
     compiled_plans: int
     plan_evictions: int
+    spans: Dict[str, SpanSummary] = dataclasses.field(default_factory=dict)
+    counters: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def shed_rate(self) -> float:
@@ -172,8 +179,10 @@ class ServerMetrics:
     def _total(self, field: str) -> int:
         return sum(getattr(c, field) for c in self._tenants.values())
 
-    def snapshot(self, *, compiled_plans: int = 0,
-                 plan_evictions: int = 0) -> MetricsSnapshot:
+    def snapshot(self, *, compiled_plans: int = 0, plan_evictions: int = 0,
+                 spans: Optional[Dict[str, SpanSummary]] = None,
+                 counters: Optional[Dict[str, int]] = None
+                 ) -> MetricsSnapshot:
         tenants = {
             name: TenantSnapshot(dataclasses.replace(c),
                                  self._tenant_hist[name].summary())
@@ -192,6 +201,8 @@ class ServerMetrics:
             ingested_docs_total=self._total("ingested_docs"),
             compiled_plans=int(compiled_plans),
             plan_evictions=int(plan_evictions),
+            spans=dict(spans or {}),
+            counters=dict(counters or {}),
         )
 
     def render(self, snapshot: Optional[MetricsSnapshot] = None, *,
@@ -202,8 +213,9 @@ class ServerMetrics:
             compiled_plans=compiled_plans, plan_evictions=plan_evictions)
         lines = []
 
-        def emit(name, value, tenant=None):
-            label = f'{{tenant="{tenant}"}}' if tenant is not None else ""
+        def emit(name, value, tenant=None, label=""):
+            if tenant is not None:
+                label = f'{{tenant="{tenant}"}}'
             v = f"{value:.6g}" if isinstance(value, float) else str(value)
             lines.append(f"cooc_serve_{name}{label} {v}")
 
@@ -232,4 +244,11 @@ class ServerMetrics:
             emit("latency_ms_p50", float(t.latency.p50_ms), tenant=name)
             emit("latency_ms_p99", float(t.latency.p99_ms), tenant=name)
             emit("latency_ms_p999", float(t.latency.p999_ms), tenant=name)
+        for name, sp in s.spans.items():
+            label = f'{{span="{name}"}}'
+            emit("span_count", sp.count, label=label)
+            emit("span_ms_total", sp.total_ms, label=label)
+            emit("span_ms_max", sp.max_ms, label=label)
+        for name, n in s.counters.items():
+            emit(name, n)
         return "\n".join(lines) + "\n"

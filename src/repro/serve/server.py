@@ -35,6 +35,16 @@ occupancy because a deadline is at risk.
 Blocking engine work (step, ingest) runs in the default executor under a
 per-lane async lock, so the event loop stays responsive and a lane never
 interleaves a step with an ingest epoch bump.
+
+* **Spans.**  One clock, ``time.perf_counter``, times latency, deadlines
+  and the lanes' spans (:mod:`repro.core.spans`): ``cooc.lane.idle`` /
+  ``cooc.lane.linger`` (the batcher waiting on an empty lane / for
+  occupancy), ``cooc.lane.lock`` (a batch out of the queue, waiting for
+  the lane behind an ingest), ``cooc.lane.batch`` (the executor's engine
+  work, whose duration the step-time model observes),
+  ``cooc.lane.resolve`` (answers back to their futures) and
+  ``cooc.lane.ingest_lock`` (an ingest waiting for the lane).  The
+  snapshot merges them with the lanes' contexts' spans.
 """
 from __future__ import annotations
 
@@ -46,6 +56,7 @@ from typing import Deque, Dict, List, Mapping, Optional, Sequence, Union
 
 from repro.core import QueryContext, canonical_exec_key, canonicalize_request
 from repro.core.query import QueryResult, QuerySpec
+from repro.core.spans import SpanLog, merge
 from repro.serve.admission import (
     AdmissionController,
     AdmissionPolicy,
@@ -132,7 +143,7 @@ class ServeResponse:
 class _Pending:
     tenant: str
     spec: QuerySpec
-    deadline_ts: float              # absolute monotonic deadline
+    deadline_ts: float              # absolute perf_counter deadline
     t_enqueue: float
     future: "asyncio.Future[ServeResponse]"
 
@@ -160,7 +171,7 @@ class _Lane:
         self.task: Optional[asyncio.Task] = None
 
     def estimate_wait_ms(self) -> float:
-        now = time.monotonic()
+        now = time.perf_counter()
         elapsed = (now - self.inflight_start) * 1e3 if self.inflight_key else 0.0
         return estimate_wait_ms(
             (canonical_exec_key(p.spec.plan_key) for p in self.pending),
@@ -184,6 +195,7 @@ class CoocServer:
         self.cfg = config
         self.ctx = ctx
         self.metrics = ServerMetrics(window=config.metrics_window)
+        self.spans = SpanLog(window=config.metrics_window)
         self.tenants: Dict[str, TenantConfig] = {}
         self._lanes: Dict[str, _Lane] = {}
         self._tenant_lane: Dict[str, str] = {}
@@ -327,7 +339,7 @@ class CoocServer:
             return ServeResponse(tenant, "shed", reason=decision.reason,
                                  est_wait_ms=decision.est_wait_ms)
 
-        now = time.monotonic()
+        now = time.perf_counter()
         budget = deadline_ms if deadline_ms is not None else (
             t.deadline_ms if t.deadline_ms is not None
             else self.cfg.default_deadline_ms)
@@ -348,9 +360,13 @@ class CoocServer:
         lane = self._lanes[self._tenant_lane[tenant]]
         if t.scope is not None:
             kwargs.setdefault("scope", t.scope)
-        async with lane.lock:
+        with self.spans.span("cooc.lane.ingest_lock"):
+            await lane.lock.acquire()
+        try:
             slots = await asyncio.get_running_loop().run_in_executor(
                 None, lambda: lane.engine.ingest_docs(doc_terms, **kwargs))
+        finally:
+            lane.lock.release()
         self.metrics.tenant(tenant).ingested_docs += len(doc_terms)
         return slots
 
@@ -372,7 +388,7 @@ class CoocServer:
             p.future.set_result(resp)
 
     def _expire(self, lane: _Lane) -> None:
-        now = time.monotonic()
+        now = time.perf_counter()
         kept = deque()
         while lane.pending:
             p = lane.pending.popleft()
@@ -386,12 +402,14 @@ class CoocServer:
 
     async def _lane_loop(self, lane: _Lane) -> None:
         loop = asyncio.get_running_loop()
+        spans = self.spans
         while True:
             if not lane.pending:
                 if self._stopping:
                     return
                 lane.event.clear()
-                await lane.event.wait()
+                with spans.span("cooc.lane.idle"):
+                    await lane.event.wait()
                 continue
             self._expire(lane)
             if not lane.pending:
@@ -403,7 +421,7 @@ class CoocServer:
             batch = [p for p in lane.pending if p.spec.plan_key == key]
             batch = batch[:lane.engine.q_batch]
 
-            now = time.monotonic()
+            now = time.perf_counter()
             pred_s = lane.model.predict(exec_key) / 1e3
             slack_s = (min(p.deadline_ts for p in batch) - now - pred_s
                        - self.cfg.margin_ms / 1e3)
@@ -413,18 +431,21 @@ class CoocServer:
                 # short of full occupancy and the oldest deadline is safe:
                 # linger for more same-plan arrivals, then re-plan
                 lane.event.clear()
-                try:
-                    await asyncio.wait_for(lane.event.wait(),
-                                           timeout=min(slack_s, linger_s))
-                except asyncio.TimeoutError:
-                    pass
+                with spans.span("cooc.lane.linger"):
+                    try:
+                        await asyncio.wait_for(lane.event.wait(),
+                                               timeout=min(slack_s, linger_s))
+                    except asyncio.TimeoutError:
+                        pass
                 continue
 
-            for p in batch:
-                lane.pending.remove(p)
-            self.metrics.note_queue_depth(len(lane.pending))
-            lane.inflight_key = exec_key
-            lane.inflight_start = time.monotonic()
+            with spans.span("cooc.lane.lock") as waited:
+                for p in batch:
+                    lane.pending.remove(p)
+                self.metrics.note_queue_depth(len(lane.pending))
+                lane.inflight_key = exec_key
+                lane.inflight_start = waited.start
+                await lane.lock.acquire()
 
             def _run_batch(reqs=batch):
                 # submit + drain + RESOLVE all inside the executor: a
@@ -432,58 +453,63 @@ class CoocServer:
                 # unresolved, i.e. it is device work — it must never run
                 # on the event loop (cooclint COOC003 enforces this
                 # lexically: no .result() in the async body below)
-                futs = []
-                for p in reqs:
-                    try:
-                        futs.append((p, lane.engine.submit(p.spec)))
-                    except Exception as e:           # e.g. unknown scope
-                        futs.append((p, e))
-                t0 = time.perf_counter()
-                lane.engine.run_until_drained()
-                step_ms = (time.perf_counter() - t0) * 1e3
-                outs = []
-                for p, fut in futs:
-                    if isinstance(fut, Exception):
-                        outs.append((p, None, fut))
-                        continue
-                    try:
-                        outs.append((p, fut.result(), None))
-                    except Exception as e:
-                        outs.append((p, None, e))
-                return outs, step_ms
+                with spans.span("cooc.lane.batch") as step:
+                    futs = []
+                    for p in reqs:
+                        try:
+                            futs.append((p, lane.engine.submit(p.spec)))
+                        except Exception as e:       # e.g. unknown scope
+                            futs.append((p, e))
+                    lane.engine.run_until_drained()
+                    outs = []
+                    for p, fut in futs:
+                        if isinstance(fut, Exception):
+                            outs.append((p, None, fut))
+                            continue
+                        try:
+                            outs.append((p, fut.result(), None))
+                        except Exception as e:
+                            outs.append((p, None, e))
+                return outs, step.ms
 
-            async with lane.lock:
+            try:
                 outs, step_ms = await loop.run_in_executor(None, _run_batch)
-            lane.model.observe(exec_key, step_ms)
-            lane.inflight_key = None
-
-            t_done = time.monotonic()
-            for p, result, exc in outs:
-                latency_ms = (t_done - p.t_enqueue) * 1e3
-                if exc is not None:
-                    self._resolve(lane, p, ServeResponse(
-                        p.tenant, "error", reason=str(exc),
-                        latency_ms=latency_ms))
-                    continue
-                if t_done > p.deadline_ts:
-                    self._resolve(lane, p, ServeResponse(
-                        p.tenant, "deadline_miss", reason="served_late",
-                        result=result, latency_ms=latency_ms))
-                else:
-                    self._resolve(lane, p, ServeResponse(
-                        p.tenant, "ok", result=result,
-                        latency_ms=latency_ms))
+            finally:
+                lane.lock.release()
+            with spans.span("cooc.lane.resolve") as resolve:
+                lane.model.observe(exec_key, step_ms)
+                lane.inflight_key = None
+                for p, result, exc in outs:
+                    latency_ms = (resolve.start - p.t_enqueue) * 1e3
+                    if exc is not None:
+                        self._resolve(lane, p, ServeResponse(
+                            p.tenant, "error", reason=str(exc),
+                            latency_ms=latency_ms))
+                        continue
+                    result.queue_ms = (waited.start - p.t_enqueue) * 1e3
+                    if resolve.start > p.deadline_ts:
+                        self._resolve(lane, p, ServeResponse(
+                            p.tenant, "deadline_miss", reason="served_late",
+                            result=result, latency_ms=latency_ms))
+                    else:
+                        self._resolve(lane, p, ServeResponse(
+                            p.tenant, "ok", result=result,
+                            latency_ms=latency_ms))
 
     # -- observability -------------------------------------------------------
 
     def snapshot(self) -> MetricsSnapshot:
         """One consistent read: per-tenant counters + pooled latency
-        quantiles + the summed executor-cache gauges across lanes."""
+        quantiles + the summed executor-cache gauges across lanes + the
+        spans and counters of the lanes and of their contexts."""
+        spans, counters = merge([self.spans] + [
+            l.engine.ctx.spans for l in self._lanes.values()])
         return self.metrics.snapshot(
             compiled_plans=sum(l.engine.compiled_plans
                                for l in self._lanes.values()),
             plan_evictions=sum(l.engine.plan_evictions_total
-                               for l in self._lanes.values()))
+                               for l in self._lanes.values()),
+            spans=spans, counters=counters)
 
     def render_metrics(self) -> str:
         return self.metrics.render(self.snapshot())
