@@ -15,10 +15,20 @@ once at ingest time, never per query):
                    padding cols (forced to -2, strictly below real -1s)
     (w, i)[b]    = top-k of the masked row, exact lax.top_k tie order
 
-Grid (nv, nw), W innermost: each W step accumulates the AND+popcount
-partial into a VMEM (B, bv) scratch block; the LAST W step applies the
-masks and folds the tile into the running (B, k) top-k held in the
-revisited output refs — the (B, V) count matrix never exists in HBM.
+Grid (nv, nw), W innermost.  With W on the lanes, summing a word block
+to one count per (b, v) is a reduction across the 128 lanes and then a
+move of the result from sublanes onto lanes; done on every W step, that
+was most of the kernel's time.  So the accumulator stays lane-wide: a
+VMEM (B, bv, 128) int32 scratch block, where lane l holds the popcount
+of the words w == l (mod 128) seen so far.  A W step adds the popcount
+of each 128-word chunk of its (bv, bw) tile elementwise, with no lane
+reduction and no relayout: block by block of (8 rows, 32 sublanes) of
+the accumulator, 32 vregs that sum the bw / 128 chunks in registers and
+use each loaded packed_t vreg for 8 rows.  The LAST W step reduces
+across the lanes once, to (B, bv), applies the masks and folds the tile
+into the running (B, k) top-k held in the revisited output refs — the
+(B, V) count matrix never exists in HBM.  A lane partial is at most
+32 × W / 128 (≈ 3,100 at CSL width): exact int32.
 
 Tie order is exact ``lax.top_k`` order (lower index wins) by the running-
 merge argument of ``materialize._topk_row_block``: running candidates come
@@ -39,6 +49,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128         # a vreg's lanes: the accumulator's width
+ROWS, SUB = 8, 32   # the W loop's register block: rows of B x sublanes of V
 
 
 def _masked_counts(counts: jax.Array, cols: jax.Array, terms: jax.Array,
@@ -92,17 +105,31 @@ def _level_step_kernel(masks_ref, pt_ref, terms_ref, valid_ref, vis_ref,
         w_out_ref[...] = jnp.full_like(w_out_ref, -2)
         i_out_ref[...] = jnp.zeros_like(i_out_ref)
 
-    m = masks_ref[...]                                       # (bb, bw) uint32
-    p = pt_ref[...]                                          # (bv, bw) uint32
-    anded = m[:, None, :] & p[None, :, :]                    # (bb, bv, bw)
-    acc_ref[...] += jnp.sum(
-        jax.lax.population_count(anded).astype(jnp.int32), axis=2)
+    # lane-wide partials: acc[r, v, l] sums popcount(masks[r, w] &
+    # packed_t[v, w]) over the words w == l (mod 128) seen so far, so the
+    # W loop is elementwise work only (no lane reduction, no relayout).
+    # A (ROWS, SUB, 128) block of it is 32 vregs: it takes the bw / 128
+    # chunks in registers, and each packed_t vreg loaded serves ROWS rows.
+    nb, bw = masks_ref.shape
+    for v0 in range(0, bv, SUB):
+        vs = slice(v0, min(v0 + SUB, bv))
+        for r0 in range(0, nb, ROWS):
+            rs = slice(r0, r0 + ROWS)
+            part = acc_ref[rs, vs, :]
+            for c in range(0, bw, LANES):
+                m = masks_ref[rs, c:c + LANES]               # (ROWS, 128)
+                p = pt_ref[vs, c:c + LANES]                  # (SUB, 128)
+                part += jax.lax.population_count(
+                    m[:, None, :] & p[None, :, :]).astype(jnp.int32)
+            acc_ref[rs, vs, :] = part
 
     @pl.when(iw == nw - 1)
     def _mask_and_merge():
+        # the V tile's one reduction across lanes: (B, bv, 128) -> (B, bv)
+        counts = jnp.sum(acc_ref[...], axis=2)
         cols = iv * bv + jax.lax.broadcasted_iota(jnp.int32, (1, bv), 1)
-        c = _masked_counts(acc_ref[...], cols, terms_ref[...],
-                           valid_ref[...], vis_ref[...], v)
+        c = _masked_counts(counts, cols, terms_ref[...], valid_ref[...],
+                           vis_ref[...], v)
         cand_w = jnp.concatenate([w_out_ref[...], c], axis=1)
         cand_i = jnp.concatenate(
             [i_out_ref[...], jnp.broadcast_to(cols, c.shape)], axis=1)
@@ -113,25 +140,30 @@ def _level_step_kernel(masks_ref, pt_ref, terms_ref, valid_ref, vis_ref,
 
 def level_step_pallas(masks: jax.Array, packed_t_pad: jax.Array,
                       terms: jax.Array, valid: jax.Array, visited: jax.Array,
-                      *, v: int, k: int, bv: int = 256, bw: int = 128,
+                      *, v: int, k: int, bv: int, bw: int,
                       interpret: bool = False):
-    """Fused level step.  masks (B, W_pad) uint32; packed_t_pad
-    (V_pad, W_pad) uint32; terms (B, 1) int32 (clipped to [0, V));
-    valid (B, 1) int32; visited (1, V_pad) int32.  Returns
-    (weights, ids) both (B, k) int32, exact ``lax.top_k`` of the masked
-    counts.  Requires B % 8 == 0, V_pad % bv == 0, W_pad % bw == 0,
-    k <= v (callers clamp k and pad the missing slots back).
+    """Fused level step.  masks (B, nw * bw) uint32, zero past packed_t's
+    words; packed_t_pad (V_pad, W_pad) uint32; terms (B, 1) int32
+    (clipped to [0, V)); valid (B, 1) int32; visited (1, V_pad) int32.
+    Returns (weights, ids) both (B, k) int32, exact ``lax.top_k`` of the
+    masked counts.  Requires B % 8 == 0, V_pad % bv == 0, bw % 128 == 0,
+    k <= v (callers clamp k and pad the missing slots back).  The last W
+    block may run past W_pad: the zero mask words AND whatever it reads
+    there to zero.
 
-    VMEM per step: the (B, bv, bw) AND intermediate dominates —
-    (32, 256, 128) is 4 MB.  The (B, k) outputs are revisited across the
+    VMEM per step: the (B, bv, 128) int32 lane-wide accumulator, B * bv
+    * 512 bytes, plus the double-buffered (bv, bw) packed_t tile and
+    (B, bw) masks.  At the engine's CSL tiles (B 32, bv 256, bw 1,024):
+    4 MiB + 2 x 1 MiB + 2 x 128 KiB, inside the default scoped VMEM, so
+    no ``vmem_limit_bytes``.  The (B, k) outputs are revisited across the
     whole grid (the running merge state), written last on each V tile.
     """
-    b, wp = masks.shape
-    vp = packed_t_pad.shape[0]
-    assert packed_t_pad.shape[1] == wp, (packed_t_pad.shape, wp)
-    assert vp % bv == 0 and wp % bw == 0, (vp, wp, bv, bw)
+    b, wm = masks.shape
+    vp, wp = packed_t_pad.shape
+    assert bw % LANES == 0 and wm == -(-wp // bw) * bw, (wm, wp, bw)
+    assert vp % bv == 0, (vp, bv)
     assert 0 < k <= v <= vp, (k, v, vp)
-    nv, nw = vp // bv, wp // bw
+    nv, nw = vp // bv, wm // bw
     kern = functools.partial(_level_step_kernel, v=v, k=k, bv=bv, nw=nw)
     return pl.pallas_call(
         kern,
@@ -149,7 +181,7 @@ def level_step_pallas(masks: jax.Array, packed_t_pad: jax.Array,
         ],
         out_shape=[jax.ShapeDtypeStruct((b, k), jnp.int32),
                    jax.ShapeDtypeStruct((b, k), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((b, bv), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((b, bv, LANES), jnp.int32)],
         interpret=interpret,
     )(masks, packed_t_pad, terms, valid, visited)
 

@@ -13,7 +13,7 @@ so callers never see shape constraints.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -190,12 +190,28 @@ def postings_counts(masks: jax.Array, packed: jax.Array, *,
 
 # -- fused BFS level step ----------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("v", "k", "dedup", "backend",
-                                             "bv", "bw"))
+def _level_step_tiles(b: int, vp: int, wp: int) -> Tuple[int, int]:
+    """(bv, bw) of the fused level-step kernel, from the shapes alone.
+
+    bv: the largest multiple of 8 dividing V_pad, at most 256 and at most
+    8,192 / B, so the (B, bv, 128) int32 lane-wide accumulator stays
+    within 4 MiB of VMEM (B = 32: bv = 256).  bw: W_pad's 128-word
+    chunks split into the fewest blocks of at most 1,024 words, as evenly
+    as whole chunks allow; the last block may overhang W_pad by under one
+    chunk per block (W_pad 12,416 = 97 chunks: 13 blocks of 1,024).
+    """
+    bv = min(256, vp, max(8, 8192 // b // 8 * 8))
+    while vp % bv:                    # vp is a multiple of 8, so
+        bv -= 8                       # this terminates at >= 8
+    chunks = wp // 128
+    nw = -(-chunks // 8)
+    return bv, 128 * -(-chunks // nw)
+
+
+@functools.partial(jax.jit, static_argnames=("v", "k", "dedup", "backend"))
 def level_step(masks: jax.Array, packed_t_pad: jax.Array, terms: jax.Array,
                valid: jax.Array, visited: jax.Array, *, v: int, k: int,
-               dedup: bool = True, backend: Optional[str] = None,
-               bv: int = 256, bw: int = 128):
+               dedup: bool = True, backend: Optional[str] = None):
     """One fused BFS level step: popcount counts + self/visited/valid
     masking + exact top-k, one launch (``kernels.level_step``).
 
@@ -236,16 +252,16 @@ def level_step(masks: jax.Array, packed_t_pad: jax.Array, terms: jax.Array,
                                    vld[:, None], vis[None, :],
                                    v=v, k=k_eff)
     else:
-        m2 = _pad_to(_pad_to(masks, 1, wp), 0, 8)
+        m2 = _pad_to(masks, 0, 8)
+        bv, bw = _level_step_tiles(m2.shape[0], vp, wp)
+        # zero mask words up to the last W block's end: where it overhangs
+        # W_pad they AND the unspecified words read there to zero
+        m2 = _pad_to(m2, 1, bw)
         t2 = _pad_to(tclip[:, None], 0, 8)
         v2 = _pad_to(vld[:, None], 0, 8)      # pad rows invalid -> all -1
         vis_p = _pad_to(vis, 0, vp)
-        bv_eff = min(bv, vp)
-        while vp % bv_eff:                    # vp is a multiple of 8, so
-            bv_eff -= 8                       # this terminates at >= 8
-        bw_eff = min(bw, wp)                  # wp % 128 == 0: always fits
         w, i = level_step_pallas(m2, packed_t_pad, t2, v2, vis_p[None, :],
-                                 v=v, k=k_eff, bv=bv_eff, bw=bw_eff,
+                                 v=v, k=k_eff, bv=bv, bw=bw,
                                  interpret=(b == "interpret"))
         w, i = w[:nb], i[:nb]
     if k_eff < k:

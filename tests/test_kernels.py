@@ -168,9 +168,19 @@ def test_postings_counts_sparse_bitmaps():
 # ---------------------------------------------------------------------------
 
 
-def _level_inputs(b, v, w, seed):
+def _level_inputs(b, v, w, seed, ties=False):
     rng = np.random.default_rng(seed)
-    packed = jnp.asarray(rng.integers(0, 1 << 32, (w, v), dtype=np.uint32))
+    if ties:
+        # 0-3 set bits per column, at random words (so in random lane
+        # chunks and W blocks): small counts, many equal, in every V tile
+        packed = np.zeros((w, v), np.uint32)
+        for col in range(v):
+            n = rng.choice(4, p=[0.3, 0.4, 0.25, 0.05])
+            for word, bit in zip(rng.integers(0, w, n), rng.integers(0, 32, n)):
+                packed[word, col] |= np.uint32(1) << np.uint32(bit)
+        packed = jnp.asarray(packed)
+    else:
+        packed = jnp.asarray(rng.integers(0, 1 << 32, (w, v), dtype=np.uint32))
     masks = jnp.asarray(rng.integers(0, 1 << 32, (b, w), dtype=np.uint32))
     terms = jnp.asarray(rng.integers(-1, v, (b,)), jnp.int32)
     valid = jnp.asarray(rng.integers(0, 2, (b,)), bool)
@@ -193,18 +203,32 @@ def _level_oracle(packed, masks, terms, valid, visited, *, k, dedup):
     return chunked_top_k(c, k)
 
 
-@pytest.mark.parametrize("b,v,w,k,dedup", [
-    (5, 97, 7, 6, True),       # ragged everything
-    (3, 40, 3, 50, False),     # k > V (clamp + pad), dedup off
-    (8, 256, 4, 8, True),      # tile-friendly B/V
-    (1, 9, 1, 9, True),        # single row, k == V
+@pytest.mark.parametrize("b,v,w,k,dedup,ties", [
+    pytest.param(5, 97, 7, 6, True, False,       # ragged everything
+                 id="5-97-7-6-True"),
+    pytest.param(3, 40, 3, 50, False, False,     # k > V (clamp + pad),
+                 id="3-40-3-50-False"),          # dedup off
+    pytest.param(8, 256, 4, 8, True, False,      # tile-friendly B/V
+                 id="8-256-4-8-True"),
+    pytest.param(1, 9, 1, 9, True, False,        # single row, k == V
+                 id="1-9-1-9-True"),
+    # several V tiles (bv 200) and 3 W blocks, the last overhanging
+    # W_pad (2,176 words: a multiple of 128, not of the W block)
+    pytest.param(8, 600, 2100, 8, True, False, id="8-600-2100-8-True"),
+    # equal counts in different lane chunks, W blocks and V tiles: the
+    # lower id must win across the lane reduction and the running merge
+    pytest.param(16, 1000, 2200, 16, True, True,
+                 id="ties-16-1000-2200-16-True"),
+    pytest.param(8, 600, 2200, 24, False, True,
+                 id="ties-8-600-2200-24-False"),
 ])
 @pytest.mark.parametrize("backend", ["xla", "interpret"])
-def test_level_step_matches_oracle_chain(b, v, w, k, dedup, backend):
+def test_level_step_matches_oracle_chain(b, v, w, k, dedup, ties, backend):
     """Fused level step == counts -> masking -> chunked_top_k, exact in
     values AND tie order, on both the compiled-XLA fallback and the
     Pallas kernel (interpret mode)."""
-    packed, masks, terms, valid, visited, pt = _level_inputs(b, v, w, b * v)
+    packed, masks, terms, valid, visited, pt = _level_inputs(b, v, w, b * v,
+                                                             ties)
     want_w, want_i = _level_oracle(packed, masks, terms, valid, visited,
                                    k=k, dedup=dedup)
     got_w, got_i = ops.level_step(masks, pt, terms, valid, visited,
